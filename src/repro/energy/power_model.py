@@ -236,7 +236,23 @@ HARDWARE = {
     "edge-orin": EDGE_ORIN,
 }
 
+#: ``jax.Device.device_kind`` -> the spec that prices that chip. A served
+#: model on an accelerator is priced by what the device reports, never by
+#: a default: a kind missing here has no spec.
+DEVICE_KINDS = {
+    "TPU v5 lite": TPU_V5E,
+}
+
 HardwareLike = Union[str, HardwareSpec]
+
+
+def hardware_for_device(device_kind: str) -> HardwareSpec:
+    """The spec of the chip whose JAX ``device_kind`` is ``device_kind``."""
+    if device_kind not in DEVICE_KINDS:
+        raise ValueError(
+            f"no hardware spec for device kind {device_kind!r}; "
+            f"known kinds: {sorted(DEVICE_KINDS)}")
+    return DEVICE_KINDS[device_kind]
 
 
 def resolve_hardware(spec: HardwareLike) -> HardwareSpec:

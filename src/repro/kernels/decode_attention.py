@@ -32,15 +32,15 @@ def _kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_ref, l_ref, acc_ref, *,
     q = q_ref[0].astype(jnp.float32) * scale              # (G, D)
     k = k_ref[0].astype(jnp.float32)                      # (bk, D)
     v = v_ref[0].astype(jnp.float32)
-    valid = valid_ref[0]                                  # (bk,) bool
+    valid = valid_ref[0] != 0                             # (1, bk)
     s = q @ k.T                                           # (G, bk)
-    s = jnp.where(valid[None, :], s, NEG_INF)
+    s = jnp.where(valid, s, NEG_INF)
 
     m_prev = m_ref[...]
     l_prev = l_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
     p = jnp.exp(s - m_new[:, None])
-    p = jnp.where(valid[None, :], p, 0.0)
+    p = jnp.where(valid, p, 0.0)
     alpha = jnp.exp(m_prev - m_new)
     l_ref[...] = alpha * l_prev + jnp.sum(p, axis=1)
     acc_ref[...] = acc_ref[...] * alpha[:, None] + p @ v
@@ -56,7 +56,11 @@ def _kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_ref, l_ref, acc_ref, *,
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
 def decode_attention_grouped(q, k, v, valid, *, block_k: int = 512,
                              interpret: bool = True):
-    """q: (BHkv, G, D); k, v: (BHkv, T, D); valid: (BHkv, T) bool."""
+    """q: (BHkv, G, D); k, v: (BHkv, T, D); valid: (BHkv, T) bool.
+
+    The mask travels as a (BHkv, 1, T) int32 array so that its block's
+    last two dimensions are (1, block_k): the TPU lowering tiles the
+    second-to-last axis by 8 unless the block spans the whole axis."""
     BHkv, G, D = q.shape
     T = k.shape[1]
     block_k = min(block_k, T)
@@ -70,7 +74,7 @@ def decode_attention_grouped(q, k, v, valid, *, block_k: int = 512,
             pl.BlockSpec((1, G, D), lambda bh, ki: (bh, 0, 0)),
             pl.BlockSpec((1, block_k, D), lambda bh, ki: (bh, ki, 0)),
             pl.BlockSpec((1, block_k, D), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k), lambda bh, ki: (bh, ki)),
+            pl.BlockSpec((1, 1, block_k), lambda bh, ki: (bh, 0, ki)),
         ],
         out_specs=pl.BlockSpec((1, G, D), lambda bh, ki: (bh, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((BHkv, G, D), q.dtype),
@@ -80,4 +84,4 @@ def decode_attention_grouped(q, k, v, valid, *, block_k: int = 512,
             pltpu.VMEM((G, D), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v, valid)
+    )(q, k, v, valid.astype(jnp.int32)[:, None, :])

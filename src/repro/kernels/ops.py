@@ -1,9 +1,10 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 These present the model-layer calling conventions ((B,S,H,D) attention
-layouts etc.), handle layout shuffling into kernel-friendly shapes, and pick
-interpret mode automatically off-TPU so the same call sites work on CPU
-(tests / dry-runs) and TPU (deployment).
+layouts etc.), handle layout shuffling into kernel-friendly shapes, and
+pick the execution mode from the default backend: compiled on a TPU,
+interpreted on the CPU (tests), and refused anywhere else, so a kernel
+never turns into interpreted HLO on an accelerator unnoticed.
 """
 from __future__ import annotations
 
@@ -18,7 +19,14 @@ from repro.kernels.ssd import ssd_scan_kernel
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"the Pallas kernels compile for a TPU and are interpreted on the "
+        f"CPU; the default backend here is {backend!r}")
 
 
 def flash_attention(q, k, v, *, causal: bool = True,

@@ -18,24 +18,26 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(x_ref, a_ref, h0_ref, y_ref, hlast_ref, h_ref, *,
+def _kernel(x_ref, a_ref, h0_ref, y_ref, hlast_ref, h_ref, a_sc, g_sc, *,
             block_s: int):
     si = pl.program_id(1)
     ns = pl.num_programs(1)
 
     @pl.when(si == 0)
     def _init():
-        h_ref[...] = h0_ref[0].astype(jnp.float32)        # (bw,)
+        h_ref[...] = h0_ref[0].astype(jnp.float32)        # (1, bw)
 
+    # gate the whole chunk at once, then walk it row by row: the TPU
+    # lowering reads a dynamic row from a ref, not from a value
     x = x_ref[0].astype(jnp.float32)                      # (bs, bw)
-    log_a = a_ref[0].astype(jnp.float32)
-    a = jnp.exp(log_a)
-    gated = jnp.sqrt(jnp.clip(1.0 - a * a, 1e-9, 1.0)) * x
+    a = jnp.exp(a_ref[0].astype(jnp.float32))
+    a_sc[...] = a
+    g_sc[...] = jnp.sqrt(jnp.clip(1.0 - a * a, 1e-9, 1.0)) * x
 
-    def step(t, carry):
-        h = carry
-        h = a[t] * h + gated[t]
-        y_ref[0, t, :] = h.astype(y_ref.dtype)
+    def step(t, h):
+        row = pl.ds(t, 1)
+        h = a_sc[row, :] * h + g_sc[row, :]               # (1, bw)
+        y_ref[0, row, :] = h.astype(y_ref.dtype)
         return h
 
     h = jax.lax.fori_loop(0, block_s, step, h_ref[...])
@@ -69,18 +71,22 @@ def rglru_scan_kernel(x, log_a, h0, *, block_w: int = 128,
                          lambda bw, si: (bw // nw, si, bw % nw)),
             pl.BlockSpec((1, block_s, block_w),
                          lambda bw, si: (bw // nw, si, bw % nw)),
-            pl.BlockSpec((1, block_w), lambda bw, si: (bw // nw, bw % nw)),
+            pl.BlockSpec((1, 1, block_w),
+                         lambda bw, si: (bw // nw, 0, bw % nw)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_s, block_w),
                          lambda bw, si: (bw // nw, si, bw % nw)),
-            pl.BlockSpec((1, block_w), lambda bw, si: (bw // nw, bw % nw)),
+            pl.BlockSpec((1, 1, block_w),
+                         lambda bw, si: (bw // nw, 0, bw % nw)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, S, W), jnp.float32),
-            jax.ShapeDtypeStruct((B, W), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, W), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((block_w,), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, block_w), jnp.float32),
+                        pltpu.VMEM((block_s, block_w), jnp.float32),
+                        pltpu.VMEM((block_s, block_w), jnp.float32)],
         interpret=interpret,
-    )(x, log_a, h0)
-    return ys, h_last
+    )(x, log_a, h0[:, None, :])
+    return ys, h_last[:, 0, :]

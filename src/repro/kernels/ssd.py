@@ -30,31 +30,38 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_out_ref,
         state_ref[...] = jnp.zeros_like(state_ref)
 
     x = x_ref[0].astype(jnp.float32)                      # (c, P)
-    dt = dt_ref[0].astype(jnp.float32)                    # (c, 1) -> (c,)
-    dt = dt[:, 0]
-    A = a_ref[0, 0]                                       # scalar for head
+    dt = dt_ref[0].astype(jnp.float32)                    # (1, c) row
+    A = a_ref[pl.program_id(0)]                           # scalar (SMEM)
     Bm = b_ref[0].astype(jnp.float32)                     # (c, N)
     Cm = c_ref[0].astype(jnp.float32)                     # (c, N)
 
-    dA = dt * A                                           # (c,)
-    seg = jnp.cumsum(dA)                                  # (c,)
+    # Prefix sums of dA as a column (over i) and a row (over j) by masked
+    # reductions of a (c, c) tile: the TPU lowering has no cumsum, and
+    # both orientations are needed for the pairwise decay below.
+    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    mask = rows >= cols                                   # j <= i
+    dA_r = jnp.broadcast_to(dt * A, (chunk, chunk))       # [i, j] = dA_j
+    dA_c = dA_r.T                                         # [i, j] = dA_i
+    seg_c = jnp.sum(jnp.where(mask, dA_r, 0.0), axis=1,
+                    keepdims=True)                        # (c, 1)
+    seg_r = jnp.sum(jnp.where(rows <= cols, dA_c, 0.0), axis=0,
+                    keepdims=True)                        # (1, c)
+    dt_c = jnp.broadcast_to(dt, (chunk, chunk)).T[:, :1]  # (c, 1)
     # intra-chunk attention-like dual form
-    li = seg[:, None]
-    lj = seg[None, :]
-    mask = (jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-            >= jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1))
-    delta = jnp.where(mask, li - lj, 0.0)
+    delta = jnp.where(mask, seg_c - seg_r, 0.0)
     decay = jnp.where(mask, jnp.exp(-delta), 0.0)         # (c, c)
-    att = (Cm @ Bm.T) * decay * dt[None, :]
+    att = (Cm @ Bm.T) * decay * dt
     y = att @ x                                           # (c, P)
     # incoming-state contribution: y_i += exp(-seg_i) * C_i . S_prev
     state = state_ref[...]                                # (P, N)
-    y = y + jnp.exp(-seg)[:, None] * (Cm @ state.T)
+    y = y + jnp.exp(-seg_c) * (Cm @ state.T)
     y_ref[0] = y.astype(y_ref.dtype)
     # state update: S' = exp(-sum dA) S + sum_j exp(-(seg_last-seg_j)) dt_j x_j B_j^T
-    w = jnp.exp(-(seg[-1] - seg)) * dt                    # (c,)
-    state_new = (jnp.exp(-jnp.sum(dA)) * state
-                 + (x * w[:, None]).T @ Bm)               # (P, N)
+    total = jnp.sum(dt * A)
+    w = jnp.exp(-(total - seg_c)) * dt_c                  # (c, 1)
+    state_new = (jnp.exp(-total) * state
+                 + (x * w).T @ Bm)                        # (P, N)
     state_ref[...] = state_new
 
     @pl.when(ci == nc - 1)
@@ -75,8 +82,8 @@ def ssd_scan_kernel(x, dt, A, B, C, *, chunk: int = 128,
     nc = s // chunk
     # layouts: head-major so each grid cell streams contiguous chunks
     xk = x.transpose(0, 2, 1, 3).reshape(b * h, s, p)
-    dtk = dt.transpose(0, 2, 1).reshape(b * h, s, 1)
-    Ak = jnp.broadcast_to(A[None], (b, h)).reshape(b * h, 1)
+    dtk = dt.transpose(0, 2, 1).reshape(b * h, 1, s)
+    Ak = jnp.broadcast_to(A[None], (b, h)).reshape(b * h)
     Bk = B.transpose(0, 2, 1, 3).reshape(b * g, s, n)
     Ck = C.transpose(0, 2, 1, 3).reshape(b * g, s, n)
 
@@ -86,8 +93,8 @@ def ssd_scan_kernel(x, dt, A, B, C, *, chunk: int = 128,
         grid=(b * h, nc),
         in_specs=[
             pl.BlockSpec((1, chunk, p), lambda bh, ci: (bh, ci, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda bh, ci: (bh, ci, 0)),
-            pl.BlockSpec((1, 1), lambda bh, ci: (bh, 0)),
+            pl.BlockSpec((1, 1, chunk), lambda bh, ci: (bh, 0, ci)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, chunk, n), lambda bh, ci: (bh // rep, ci, 0)),
             pl.BlockSpec((1, chunk, n), lambda bh, ci: (bh // rep, ci, 0)),
         ],

@@ -1,8 +1,9 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: prove every (architecture x input-shape x mesh)
 combination lowers, SPMD-partitions, and compiles.
+
+A host-CPU tool: importing it asks for 512 placeholder CPU devices (added
+to any ``XLA_FLAGS`` already set) and, unless ``JAX_PLATFORMS`` says
+otherwise, keeps JAX on the CPU, so it never takes an attached chip.
 
 For each combination this builds the jitted step (train_step / prefill /
 serve_step) with explicit in/out shardings, lowers it against
@@ -14,6 +15,13 @@ Usage:
   python -m repro.launch.dryrun --arch tinyllama-1.1b --shape train_4k
   python -m repro.launch.dryrun --all --mesh both --out results/dryrun
 """
+import os
+
+os.environ["XLA_FLAGS"] = " ".join(filter(None, [
+    os.environ.get("XLA_FLAGS"),
+    "--xla_force_host_platform_device_count=512"]))
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
 import argparse      # noqa: E402
 import functools     # noqa: E402
 import json          # noqa: E402
@@ -224,20 +232,11 @@ def _cost_variant(cfg, u: int):
     return cfg.replace(num_layers=prefix + u, unroll_layers=True)
 
 
-def _cost_dict(compiled) -> dict:
-    """cost_analysis() returns a dict on jax >= 0.6 but a one-element list
-    of dicts on older releases; normalize to a dict."""
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def _compile_cost(arch, shape_name, mesh, cfg, donate: bool = False):
     fn, args = build_lowering(arch, shape_name, mesh, cfg_override=cfg,
                               donate=donate)
     compiled = fn.lower(*args).compile()
-    cost = _cost_dict(compiled)
+    cost = compiled.cost_analysis() or {}
     coll = collective_bytes(compiled.as_text())
     mem = compiled.memory_analysis()
     return {"flops": cost.get("flops", 0.0),
@@ -292,7 +291,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
         lowered = fn.lower(*args)
         compiled = lowered.compile()
         mem = compiled.memory_analysis()
-        cost = _cost_dict(compiled)
+        cost = compiled.cost_analysis() or {}
         coll = collective_bytes(compiled.as_text())
         extra = cost_extrapolated(arch, shape_name, mesh) \
             if extrapolate else None

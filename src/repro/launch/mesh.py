@@ -6,16 +6,10 @@ jax device state (device count is locked at first jax init, and only
 from __future__ import annotations
 
 import jax
-
-try:                                   # jax >= 0.5
-    from jax.sharding import AxisType
-except ImportError:                    # older jax: meshes are Auto-only
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 

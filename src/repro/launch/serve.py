@@ -24,20 +24,29 @@ cluster — under a workload with any registered power policy (or none).
   # can still meet the request's TTFT tier
   python -m repro.launch.serve --nodes 4 --hardware a6000,h100:2,l4 \
       --router energy --policy agft
+  # the model itself on the default JAX device (one TPU chip): real
+  # forwards of llama3-3b at its published widths, priced by the spec of
+  # the chip the device reports
+  python -m repro.launch.serve --backend jax --arch llama3-3b \
+      --workload normal --requests 8 --policy agft
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
+from typing import List, Optional, Tuple
 
 import numpy as np
 
 from repro.configs import get_config
-from repro.energy import HARDWARE, parse_fleet_hardware, resolve_hardware
+from repro.energy import (HARDWARE, HardwareSpec, hardware_for_device,
+                          parse_fleet_hardware, resolve_hardware)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.policies import available_policies, get_policy
 from repro.serving import (FAULT_PRESETS, NETWORK_PRESETS,
                            POLICY_TICK_MODES, EngineConfig,
-                           InferenceEngine, NetworkModel)
+                           InferenceEngine, JaxBackend, NetworkModel)
 from repro.serving.cluster import ROUTERS, ServingCluster
 from repro.workloads import (PROTOTYPES, generate_azure_trace,
                              generate_requests)
@@ -48,6 +57,60 @@ def build_engine(arch: str, hardware_name: str = "a6000",
     hw = resolve_hardware(hardware_name)
     return InferenceEngine(get_config(arch), engine_cfg or EngineConfig(),
                            hardware=hw, initial_frequency=hw.f_max)
+
+
+#: device batch of ``--backend jax``; the scheduler admits as many decode
+#: sequences, so every scheduled sequence runs on the device
+JAX_MAX_BATCH = 8
+
+
+def device_hardware(requested: Optional[str], platform: str,
+                    device_kind: str) -> HardwareSpec:
+    """The spec that prices a model served on ``device_kind``.
+
+    On the CPU platform (tests) the requested spec is used, as in the
+    simulator. On an accelerator the device decides: a kind with no spec,
+    or a ``requested`` spec that disagrees with the device's, raises
+    ``ValueError``.
+    """
+    if platform == "cpu":
+        return resolve_hardware(requested or "a6000")
+    hw = hardware_for_device(device_kind)
+    if requested is not None and resolve_hardware(requested) != hw:
+        raise ValueError(
+            f"--hardware {requested} disagrees with the device: "
+            f"{device_kind!r} is priced as {hw.name}")
+    return hw
+
+
+def cache_len_for(workload: str, requests) -> int:
+    """Device cache length covering the workload's longest prompt plus
+    output, rounded up to a power of two (a prototype's bound, or the
+    longest generated request of a trace)."""
+    spec = PROTOTYPES.get(workload)
+    if spec is not None:
+        longest = spec.context_range[1] + spec.generation_range[1]
+    else:
+        longest = max(r.prompt_len + r.output_len for r in requests)
+    return 1 << (longest - 1).bit_length()
+
+
+@contextlib.contextmanager
+def count_compiles():
+    """Yield a list that collects the name of every JAX trace, lowering
+    and compilation that happens inside the block."""
+    import jax.monitoring
+    events: List[str] = []
+
+    def on_event(name, _secs, **_kw):
+        if name.startswith("/jax/core/compile/"):
+            events.append(name)
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        yield events
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
 
 
 def summarize(engine: InferenceEngine, tuner=None) -> dict:
@@ -200,15 +263,21 @@ def _serve_cluster(args) -> dict:
     return out
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3-3b")
-    ap.add_argument("--hardware", default="a6000",
+    ap.add_argument("--backend", default="sim", choices=["sim", "jax"],
+                    help="'sim' prices every iteration with the DVFS model; "
+                         "'jax' runs the model on the default JAX device "
+                         "(one node)")
+    ap.add_argument("--hardware", default=None,
                     help="hardware spec name "
                          f"({', '.join(sorted(HARDWARE))}) or, with "
                          "--nodes N, a mixed-fleet spec string like "
                          "'a6000,h100:2,l4' (name[:count] entries; counts "
-                         "must sum to N; one bare name broadcasts)")
+                         "must sum to N; one bare name broadcasts). "
+                         "Default a6000; with --backend jax on an "
+                         "accelerator, the device's own spec")
     ap.add_argument("--router", default="least-loaded",
                     choices=sorted(ROUTERS),
                     help="cluster request placement: 'least-loaded' "
@@ -260,35 +329,97 @@ def main():
                          "POLICY_TICK events, windows cut at tick time)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default="")
-    args = ap.parse_args()
+    return ap
+
+
+def run(argv=None) -> Tuple[dict, Optional[InferenceEngine]]:
+    """Serve as ``argv`` says, print the summary (and write it to
+    ``--out``), and return it with the single-node engine (None for the
+    cluster path)."""
+    ap = build_parser()
+    args = ap.parse_args(argv)
 
     if args.fleet_policy != "none" and args.nodes < 2:
         ap.error("--fleet-policy needs --nodes >= 2")
     # network routing, fault injection and pure policy ticks live in the
     # cluster/event-loop path; a single node becomes a 1-node cluster
-    if (args.nodes > 1 or args.network_model != "none"
-            or args.faults != "none"
-            or args.policy_tick_mode != "iteration"):
+    cluster = (args.nodes > 1 or args.network_model != "none"
+               or args.faults != "none"
+               or args.policy_tick_mode != "iteration")
+    if cluster and args.backend == "jax":
+        ap.error("--backend jax serves one node on one device; --nodes, "
+                 "--network-model, --faults and --policy-tick-mode need "
+                 "the simulator")
+    eng = None
+    if cluster:
+        if args.hardware is None:
+            args.hardware = "a6000"
         summary = _serve_cluster(args)
     else:
-        eng = build_engine(args.arch, args.hardware)
-        eng.submit(_generate(args))
-        tuner = None
-        if args.policy != "none":
-            kw = ({"frequency_mhz": args.frequency}
-                  if args.policy in ("static", "oracle") and args.frequency
-                  else {})
-            tuner = get_policy(args.policy,
-                               hardware=resolve_hardware(args.hardware),
-                               **kw)
-        elif args.frequency:
-            eng.set_frequency(args.frequency)
-        eng.drain(policy=tuner)
-        summary = summarize(eng, tuner)
+        summary, eng = _serve_node(ap, args)
     print(json.dumps(summary, indent=1))
     if args.out:
         with open(args.out, "w") as f:
             json.dump(summary, f, indent=1)
+    return summary, eng
+
+
+def _serve_node(ap, args) -> Tuple[dict, InferenceEngine]:
+    requests = _generate(args)
+    device = {}
+    if args.backend == "jax":
+        # one node over JaxBackend: the full --arch config on the default
+        # JAX device, priced by the spec of the chip it reports
+        import jax
+        devices = jax.devices()
+        dev = devices[0]
+        try:
+            hw = device_hardware(args.hardware, dev.platform,
+                                 dev.device_kind)
+        except ValueError as e:
+            ap.error(str(e))
+        cfg = get_config(args.arch)
+        backend = JaxBackend(
+            cfg, hw, max_batch=JAX_MAX_BATCH,
+            cache_len=cache_len_for(args.workload, requests),
+            seed=args.seed)
+        eng = InferenceEngine(cfg, EngineConfig(max_num_seqs=JAX_MAX_BATCH),
+                              hardware=hw, backend=backend,
+                              initial_frequency=hw.f_max)
+        device = {"platform": dev.platform, "device_kind": dev.device_kind,
+                  "device_count": len(devices),
+                  "cache_len": backend.cache_len,
+                  "max_batch": backend.max_batch,
+                  "compile_s": backend.compile_s}
+    else:
+        eng = build_engine(args.arch, args.hardware or "a6000")
+    eng.submit(requests)
+    tuner = None
+    if args.policy != "none":
+        kw = ({"frequency_mhz": args.frequency}
+              if args.policy in ("static", "oracle") and args.frequency
+              else {})
+        tuner = get_policy(args.policy, hardware=eng.hardware, **kw)
+    elif args.frequency:
+        eng.set_frequency(args.frequency)
+    if args.backend == "jax":
+        with count_compiles() as compiles:
+            eng.drain(policy=tuner)
+        decode_s = eng.backend.decode_s
+        device["serve_compiles"] = len(compiles)
+        device["decode_steps"] = len(decode_s)
+        device["decode_ms_median"] = (
+            float(np.median(decode_s)) * 1e3 if decode_s else None)
+    else:
+        eng.drain(policy=tuner)
+    summary = summarize(eng, tuner)
+    summary.update(device)
+    return summary, eng
+
+
+def main(argv=None) -> Tuple[dict, Optional[InferenceEngine]]:
+    enable_compile_cache()
+    return run(argv)
 
 
 if __name__ == "__main__":

@@ -3,9 +3,10 @@ backends and a simulated clock.
 
 ``SimBackend`` prices each iteration with the analytical DVFS model (the
 paper's evaluation environment); ``JaxBackend`` executes real JAX forwards
-of a (reduced) model so the whole serving stack can be integration-tested
-end-to-end on CPU. Both expose identical (latency, energy, power) effects,
-so AGFT drives either transparently through ``set_frequency``.
+of the model on the default device — a TPU when served through
+``launch.serve --backend jax``, the CPU (reduced configs) in tests. Both
+expose identical (latency, energy, power) effects, so AGFT drives either
+transparently through ``set_frequency``.
 
 The engine is a discrete-event process: future arrivals live in a heap
 (O(log n) ``submit``, no re-sorts), and ``next_event_time`` tells the
@@ -30,6 +31,7 @@ from __future__ import annotations
 import dataclasses
 import heapq
 import itertools
+import time
 from typing import List, Optional, Tuple
 
 import numpy as np
@@ -183,52 +185,83 @@ class SimBackend:
 
 
 class JaxBackend:
-    """Real-execution backend for integration tests: runs the actual model
-    (reduced config) per iteration and prices energy off measured wall time.
+    """Real-execution backend: runs the model's prefill and decode on the
+    default JAX device each iteration and prices energy off measured wall
+    time.
+
+    The constructor initialises the parameters and the cache in jitted
+    programs, then compiles decode and every prefill bucket and runs each
+    once (``compile_s``). ``execute`` calls only those compiled
+    executables, so serving never traces or compiles; a shape outside the
+    warmed set raises instead of compiling.
     """
+
+    #: prefill runs at most this many tokens, zero-padded up to a power of
+    #: two: buckets 1, 2, 4, ..., 64
+    PREFILL_MAX = 64
 
     def __init__(self, cfg: ModelConfig, hardware: HardwareSpec = A6000,
                  max_batch: int = 8, cache_len: int = 256, seed: int = 0):
         import jax
-        import jax.numpy as jnp
         from repro.models import build_model
         self.cfg = cfg
         self.dvfs = DVFSModel(hardware)
-        self.model = build_model(cfg)
-        self.params = self.model.init(jax.random.PRNGKey(seed))
+        self.model = model = build_model(cfg)
         self.max_batch = max_batch
         self.cache_len = cache_len
-        self.cache = self.model.init_cache(max_batch, cache_len)
-        self._jax = jax
-        self._jnp = jnp
-        self._decode = jax.jit(self.model.decode_step)
-        self._prefill = jax.jit(
-            lambda p, t: self.model.forward(p, t)[0])
+        self.params = jax.jit(model.init)(jax.random.PRNGKey(seed))
+        self.cache = jax.jit(model.init_cache, static_argnums=(0, 1))(
+            max_batch, cache_len)
+        #: wall seconds of each decode call (device time: every call ends
+        #: in ``block_until_ready``)
+        self.decode_s: List[float] = []
+
+        t0 = time.perf_counter()
+        self._token = jax.device_put(np.zeros((max_batch, 1), np.int32))
+        pos = np.ones((max_batch,), np.int32)
+        # the cache is donated: decode updates it in place
+        self._decode = jax.jit(model.decode_step, donate_argnums=(2,)).lower(
+            self.params, self._token, self.cache, pos).compile()
+        prefill = jax.jit(lambda p, t: model.forward(p, t)[0])
+        self._prefill = {}
+        n = 1
+        while n <= self.PREFILL_MAX:
+            toks = jax.device_put(np.zeros((1, n), np.int32))
+            fn = prefill.lower(self.params, toks).compile()
+            fn(self.params, toks).block_until_ready()
+            self._prefill[n] = (fn, toks)
+            n *= 2
+        logits, self.cache = self._decode(self.params, self._token,
+                                          self.cache, pos)
+        logits.block_until_ready()
+        #: warm-up seconds: compiling decode and every prefill bucket and
+        #: running each once, before the first request
+        self.compile_s = time.perf_counter() - t0
 
     def execute(self, plan: BatchPlan, f_mhz: float
                 ) -> Tuple[float, float, float]:
-        import time
-        jnp = self._jnp
+        b = self.max_batch
+        if len(plan.decode) > b:
+            raise ValueError(
+                f"plan decodes {len(plan.decode)} sequences; the device "
+                f"batch is {b} (set EngineConfig.max_num_seqs <= {b})")
         t0 = time.perf_counter()
         if plan.prefill_tokens:
-            # bucket prefill lengths to powers of two (zero-pad): the jitted
-            # forward retraces per distinct shape, so without bucketing every
-            # novel prompt length recompiles; with it there are at most
-            # log2(64)+1 prefill traces per process.
-            n = min(plan.prefill_tokens, 64)
-            n = 1 << (max(n, 1) - 1).bit_length()
-            toks = jnp.zeros((1, n), jnp.int32)
-            self._prefill(self.params, toks).block_until_ready()
+            # bucket prefill lengths to powers of two (zero-pad): one
+            # compiled program per bucket, all built in the warm-up
+            n = min(plan.prefill_tokens, self.PREFILL_MAX)
+            fn, toks = self._prefill[1 << (max(n, 1) - 1).bit_length()]
+            fn(self.params, toks).block_until_ready()
         if plan.decode:
-            b = self.max_batch
-            tok = jnp.zeros((b, 1), jnp.int32)
-            pos = jnp.minimum(
-                jnp.array([r.context_len for r in plan.decode[:b]]
-                          + [1] * max(0, b - len(plan.decode)),
-                          jnp.int32), self.cache_len - 1)
-            logits, self.cache = self._decode(self.params, tok, self.cache,
-                                              pos)
+            t1 = time.perf_counter()
+            pos = np.minimum(
+                np.array([r.context_len for r in plan.decode]
+                         + [1] * (b - len(plan.decode)), np.int32),
+                self.cache_len - 1)
+            logits, self.cache = self._decode(self.params, self._token,
+                                              self.cache, pos)
             logits.block_until_ready()
+            self.decode_s.append(time.perf_counter() - t1)
         wall = time.perf_counter() - t0
         # price energy with the DVFS power model at measured utilization
         fr = f_mhz / self.dvfs.spec.f_max
