@@ -19,6 +19,7 @@ from typing import List, Optional
 
 import numpy as np
 
+from repro import tracing
 from repro.core.features import FeatureExtractor, FeatureScales
 from repro.core.linucb import LinUCBBank
 from repro.core.monitor import TelemetryMonitor
@@ -142,6 +143,10 @@ class AGFTTuner:
         return self.act(engine, now=now)
 
     def act(self, engine, now: Optional[float] = None) -> float:
+        with tracing.span("agft.decide"):
+            return self._act(engine, now)
+
+    def _act(self, engine, now: Optional[float]) -> float:
         # fault surface (None on healthy engines — the zero-fault path
         # pays one attribute read and stays decision-identical)
         fs = (getattr(engine, "fault_state", None)

@@ -39,6 +39,7 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
+from repro import tracing
 from repro.configs import get_config
 from repro.energy import (HARDWARE, HardwareSpec, hardware_for_device,
                           parse_fleet_hardware, resolve_hardware)
@@ -403,13 +404,23 @@ def _serve_node(ap, args) -> Tuple[dict, InferenceEngine]:
     elif args.frequency:
         eng.set_frequency(args.frequency)
     if args.backend == "jax":
-        with count_compiles() as compiles:
-            eng.drain(policy=tuner)
-        decode_s = eng.backend.decode_s
+        tracing.reset()
+        tracing.enable()
+        try:
+            with count_compiles() as compiles:
+                eng.drain(policy=tuner)
+        finally:
+            tracing.disable()
+        # each decode call's wall time, from building its inputs to the
+        # end of its ``block_until_ready``
+        decode_ms = [(e - s) * 1e-6
+                     for n, s, e, _ in tracing.records()["spans"]
+                     if n == "device.decode"]
+        tracing.reset()
         device["serve_compiles"] = len(compiles)
-        device["decode_steps"] = len(decode_s)
+        device["decode_steps"] = len(decode_ms)
         device["decode_ms_median"] = (
-            float(np.median(decode_s)) * 1e3 if decode_s else None)
+            float(np.median(decode_ms)) if decode_ms else None)
     else:
         eng.drain(policy=tuner)
     summary = summarize(eng, tuner)

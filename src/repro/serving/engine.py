@@ -36,6 +36,7 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
+from repro import tracing
 from repro.energy import A6000, CostModel, DVFSModel, HardwareSpec
 from repro.models.common import ModelConfig
 from repro.serving.driver import EngineNode, drive
@@ -212,9 +213,6 @@ class JaxBackend:
         self.params = jax.jit(model.init)(jax.random.PRNGKey(seed))
         self.cache = jax.jit(model.init_cache, static_argnums=(0, 1))(
             max_batch, cache_len)
-        #: wall seconds of each decode call (device time: every call ends
-        #: in ``block_until_ready``)
-        self.decode_s: List[float] = []
 
         t0 = time.perf_counter()
         self._token = jax.device_put(np.zeros((max_batch, 1), np.int32))
@@ -246,22 +244,31 @@ class JaxBackend:
                 f"plan decodes {len(plan.decode)} sequences; the device "
                 f"batch is {b} (set EngineConfig.max_num_seqs <= {b})")
         t0 = time.perf_counter()
-        if plan.prefill_tokens:
+        planned = plan.prefill_tokens
+        if planned:
             # bucket prefill lengths to powers of two (zero-pad): one
             # compiled program per bucket, all built in the warm-up
-            n = min(plan.prefill_tokens, self.PREFILL_MAX)
+            n = min(planned, self.PREFILL_MAX)
+            tracing.add("device.prefill_tokens_planned", planned)
+            tracing.add("device.prefill_tokens_computed", n)
             fn, toks = self._prefill[1 << (max(n, 1) - 1).bit_length()]
-            fn(self.params, toks).block_until_ready()
+            with tracing.span("device.prefill"):
+                with tracing.span("device.prefill.launch"):
+                    out = fn(self.params, toks)
+                with tracing.span("device.prefill.wait"):
+                    out.block_until_ready()
         if plan.decode:
-            t1 = time.perf_counter()
-            pos = np.minimum(
-                np.array([r.context_len for r in plan.decode]
-                         + [1] * (b - len(plan.decode)), np.int32),
-                self.cache_len - 1)
-            logits, self.cache = self._decode(self.params, self._token,
-                                              self.cache, pos)
-            logits.block_until_ready()
-            self.decode_s.append(time.perf_counter() - t1)
+            with tracing.span("device.decode"):
+                with tracing.span("device.decode.inputs"):
+                    pos = np.minimum(
+                        np.array([r.context_len for r in plan.decode]
+                                 + [1] * (b - len(plan.decode)), np.int32),
+                        self.cache_len - 1)
+                with tracing.span("device.decode.launch"):
+                    logits, self.cache = self._decode(
+                        self.params, self._token, self.cache, pos)
+                with tracing.span("device.decode.wait"):
+                    logits.block_until_ready()
         wall = time.perf_counter() - t0
         # price energy with the DVFS power model at measured utilization
         fr = f_mhz / self.dvfs.spec.f_max
@@ -486,15 +493,19 @@ class InferenceEngine:
         """Execute one continuous-batching iteration at the current clock
         (the scheduler is expected to hold work; otherwise this is a
         blocked tick)."""
+        with tracing.span("engine.iteration"):
+            return self._iterate()
+
+    def _iterate(self) -> List[Request]:
         sched = self.sched
-        plan = sched.schedule(self.clock)
-        if not plan.prefill and not plan.decode:     # inlined plan.empty
-            # blocked (e.g. out of KV blocks): try preemption, else idle-tick
-            if not sched._preempt_lowest_priority():
-                return self._blocked_tick()
+        with tracing.span("sched.plan"):
             plan = sched.schedule(self.clock)
-            if plan.empty:
-                return self._blocked_tick()
+            # blocked (e.g. out of KV blocks): try preemption, else idle-tick
+            if (not plan.prefill and not plan.decode     # inlined plan.empty
+                    and sched._preempt_lowest_priority()):
+                plan = sched.schedule(self.clock)
+        if not plan.prefill and not plan.decode:
+            return self._blocked_tick()
 
         # prefix-cache credit must be read BEFORE completion advances
         # ``prefilled`` (a request is on its first chunk exactly while
@@ -509,48 +520,49 @@ class InferenceEngine:
         else:
             dt, energy, power = self._execute_phased(plan)
         self.clock += dt
-        finished = sched.complete_iteration(plan, self.clock)
-        if finished:
-            self.finished.extend(finished)
+        with tracing.span("sched.complete"):
+            finished = sched.complete_iteration(plan, self.clock)
+            if finished:
+                self.finished.extend(finished)
 
-        # metrics (one pass over the prefill half; comparisons inline the
-        # Request properties — hot path)
-        prefill_tok = 0
-        gen_from_prefill = 0
-        for r, n in plan.prefill:
-            prefill_tok += n
-            if r.prefilled >= r.prompt_len:
-                gen_from_prefill += 1
-        c = self.metrics.c
-        c.prompt_tokens_total += prefill_tok
-        c.cached_prompt_tokens_total += cached_tok
-        c.generation_tokens_total += len(plan.decode) + gen_from_prefill
-        c.iterations_total += 1
-        c.requests_finished_total += len(finished)
-        c.requests_dropped_total = len(sched.dropped)
-        # TTFT is accounted when the scheduler assigns first_token_time —
-        # not by replaying a float-equality check against the clock, which
-        # could silently drop samples. (Guarded: the event list is empty on
-        # almost every iteration — skip the drain call + list churn.)
-        if sched._first_token_events:
-            for r in sched.pop_first_token_events():
-                c.ttft_seconds_total += r.first_token_time - r.arrival_time
-                c.ttft_count_total += 1
-        stats = self.kv.stats
-        c.prefix_cache_hits_total = stats.hits
-        c.prefix_cache_queries_total = stats.queries
-        c.energy_joules_total += energy
-        c.busy_seconds_total += dt
-        c.requests_running = len(sched.running)
-        # waiting = queued at the scheduler + owned-but-not-yet-ingested,
-        # wherever those live (this engine's heap or the network path) —
-        # identical totals for direct submit and zero-delay delivery
-        c.requests_waiting = (len(sched.waiting) + len(self._pending)
-                              + self.inflight)
-        c.gpu_cache_usage = self.kv.usage
-        c.current_frequency_mhz = self.frequency
-        c.current_power_watts = power
-        return finished
+            # metrics (one pass over the prefill half; comparisons inline the
+            # Request properties — hot path)
+            prefill_tok = 0
+            gen_from_prefill = 0
+            for r, n in plan.prefill:
+                prefill_tok += n
+                if r.prefilled >= r.prompt_len:
+                    gen_from_prefill += 1
+            c = self.metrics.c
+            c.prompt_tokens_total += prefill_tok
+            c.cached_prompt_tokens_total += cached_tok
+            c.generation_tokens_total += len(plan.decode) + gen_from_prefill
+            c.iterations_total += 1
+            c.requests_finished_total += len(finished)
+            c.requests_dropped_total = len(sched.dropped)
+            # TTFT is accounted when the scheduler assigns first_token_time —
+            # not by replaying a float-equality check against the clock, which
+            # could silently drop samples. (Guarded: the event list is empty on
+            # almost every iteration — skip the drain call + list churn.)
+            if sched._first_token_events:
+                for r in sched.pop_first_token_events():
+                    c.ttft_seconds_total += r.first_token_time - r.arrival_time
+                    c.ttft_count_total += 1
+            stats = self.kv.stats
+            c.prefix_cache_hits_total = stats.hits
+            c.prefix_cache_queries_total = stats.queries
+            c.energy_joules_total += energy
+            c.busy_seconds_total += dt
+            c.requests_running = len(sched.running)
+            # waiting = queued at the scheduler + owned-but-not-yet-ingested,
+            # wherever those live (this engine's heap or the network path) —
+            # identical totals for direct submit and zero-delay delivery
+            c.requests_waiting = (len(sched.waiting) + len(self._pending)
+                                  + self.inflight)
+            c.gpu_cache_usage = self.kv.usage
+            c.current_frequency_mhz = self.frequency
+            c.current_power_watts = power
+            return finished
 
     # ------------------------------------------------------------------
     def run_until(self, t_end: float, policy=None, *, tuner=None) -> None:

@@ -22,6 +22,7 @@ import dataclasses
 from collections import deque
 from typing import Deque, Dict, List, Tuple
 
+from repro import tracing
 from repro.serving.kv_cache import PagedKVCache
 from repro.serving.request import Request, RequestState
 
@@ -76,6 +77,8 @@ class ContinuousBatchingScheduler:
         req.state = RequestState.WAITING
         if req.deadline_s is not None:
             self._has_deadlines = True
+        if req.first_scheduled_time is None:
+            tracing.begin("request.queued", req.request_id)
         self.waiting.append(req)
 
     def set_admission_cap(self, cap) -> None:
@@ -139,6 +142,8 @@ class ContinuousBatchingScheduler:
                 req.state = RequestState.RUNNING
                 if req.first_scheduled_time is None:
                     req.first_scheduled_time = now
+                    tracing.end("request.queued", req.request_id)
+                    tracing.begin("request.prefill", req.request_id)
                 # prefix-cache hits skip that prefill work
                 req.prefilled = req.cached_tokens
                 self.running[req.request_id] = req
@@ -213,6 +218,7 @@ class ContinuousBatchingScheduler:
                 req.generated += 1
                 if req.first_token_time is None:
                     req.first_token_time = now
+                    tracing.end("request.prefill", req.request_id)
                     self._first_token_events.append(req)
                 self.kv.register_prefix(req)
                 if req.generated >= req.output_len:

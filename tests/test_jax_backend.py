@@ -3,6 +3,7 @@ tinyllama) through the JaxBackend, with AGFT attached — proves the tuner is
 backend-agnostic (it only sees metrics + set_frequency)."""
 import pytest
 
+from repro import tracing
 from repro.configs import get_config
 from repro.core import AGFTConfig, AGFTTuner
 from repro.energy import A6000
@@ -32,11 +33,18 @@ def test_engine_with_real_jax_execution(backend):
     eng.submit(reqs)
     tuner = AGFTTuner(A6000, AGFTConfig(sampling_period_s=0.2))
     # every program was compiled in the backend's warm-up
-    with count_compiles() as compiles:
-        eng.drain(policy=tuner, max_iters=2000)
+    tracing.reset()
+    tracing.enable()
+    try:
+        with count_compiles() as compiles:
+            eng.drain(policy=tuner, max_iters=2000)
+    finally:
+        tracing.disable()
+    spans = [n for n, *_ in tracing.records()["spans"]]
+    tracing.reset()
     assert compiles == []
     assert backend.compile_s > 0
-    assert backend.decode_s
+    assert "device.decode" in spans
     assert len(eng.finished) == 6
     assert eng.metrics.c.energy_joules_total > 0
     assert all(r.generated == r.output_len for r in eng.finished)
