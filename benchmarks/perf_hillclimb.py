@@ -10,7 +10,8 @@ the hypothesis -> change -> measure -> validate loop, driven from the
 compiled HLO because this container has no TPU clock.
 
   PYTHONPATH=src python -m benchmarks.perf_hillclimb \
-      --pairs llama4-scout-17b-a16e:train_4k phi3-medium-14b:decode_32k
+      --pairs llama4-scout-17b-a16e:train_4k phi3-medium-14b:decode_32k \
+      --variants chunked_attention capacity_moe
 """
 import argparse      # noqa: E402
 import json          # noqa: E402
@@ -39,15 +40,6 @@ def terms(costs: dict) -> dict:
 # ---------------------------------------------------------------------------
 
 VARIANTS = {
-    "scatter_kv": (
-        "decode cache write via dynamic_update_slice instead of one-hot "
-        "blend: removes one full cache read+write per step -> memory term "
-        "down by ~cache_bytes/HBM_bw",
-        lambda c: c.replace(kv_update="scatter"), False),
-    "scatter_kv_donated": (
-        "scatter + donated cache buffers: XLA aliases the cache in-place, "
-        "eliminating the copy the undonated scatter must make",
-        lambda c: c.replace(kv_update="scatter"), True),
     "no_remat": (
         "training without activation checkpointing: compute term down "
         "~25-30% (no recompute) at the cost of activation memory",
@@ -84,10 +76,9 @@ VARIANTS = {
         lambda c: c.replace(moe_dispatch="capacity",
                             ref_attention="chunked"), False),
     "all_opts": (
-        "chunked attention + capacity MoE + scatter KV + donation",
+        "chunked attention + capacity MoE + donation",
         lambda c: c.replace(moe_dispatch="capacity",
-                            ref_attention="chunked",
-                            kv_update="scatter"), True),
+                            ref_attention="chunked"), True),
 }
 
 
@@ -131,8 +122,8 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--pairs", nargs="+", required=True,
                     help="arch:shape entries")
-    ap.add_argument("--variants", nargs="+",
-                    default=["scatter_kv", "scatter_kv_donated"])
+    ap.add_argument("--variants", nargs="+", required=True,
+                    choices=sorted(VARIANTS))
     ap.add_argument("--out", default="results/perf_hillclimb.json")
     args = ap.parse_args()
 

@@ -261,31 +261,44 @@ def _cache_from_prefill(cfg: ModelConfig, k, v, window: int,
     return KVCache(k=k, v=v)
 
 
-def scatter_cache_update(cache_arr: jnp.ndarray, new_vals: jnp.ndarray,
-                         slot: jnp.ndarray) -> jnp.ndarray:
-    """In-place-style cache write: O(B*H*D) traffic instead of the one-hot
-    formulation's full O(B*T*H*D) read+write (a §Perf optimization — see
-    EXPERIMENTS.md). cache (B,T,...), new (B,1,...), slot (B,)."""
-    def upd(c, v, s):
-        idx = (s,) + (0,) * (c.ndim - 1)
-        return jax.lax.dynamic_update_slice(c, v.astype(c.dtype), idx)
-    return jax.vmap(upd)(cache_arr, new_vals, slot)
+def _write_cache(cache_arr: jnp.ndarray, new_vals: jnp.ndarray,
+                 slot: jnp.ndarray,
+                 layer: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """Write each row's one new entry where it lives, and nothing else:
+    ``cache_arr[layer, b, slot[b]] = new_vals[b, 0]``. cache (B,T,...), or
+    with ``layer`` the stacked (L,B,T,...); new (B,1,...); slot (B,)."""
+    new = new_vals[:, 0].astype(cache_arr.dtype)
+    # index every dim but the last: an update window over (Hkv, D) would
+    # pin Hkv next to D, and where the compiler lays T out between them
+    # (phi3's 10 KV heads) the whole cache is relaid out around the scan
+    rows, *mid = jnp.ix_(jnp.arange(new.shape[0]),
+                         *map(jnp.arange, new.shape[1:-1]))
+    idx = (rows, slot.reshape(rows.shape), *mid)
+    if layer is not None:
+        idx = (layer,) + idx
+    return cache_arr.at[idx].set(new, indices_are_sorted=True,
+                                 unique_indices=True)
 
 
-def _write_cache(cfg: ModelConfig, cache_arr, new_vals, slot):
-    if cfg.kv_update == "scatter":
-        return scatter_cache_update(cache_arr, new_vals, slot)
-    cache_len = cache_arr.shape[1]
-    onehot = jax.nn.one_hot(slot, cache_len, dtype=new_vals.dtype)
-    expand = onehot.reshape(onehot.shape + (1,) * (cache_arr.ndim - 2))
-    return cache_arr * (1 - expand) + expand * new_vals
+def _layer_of(cache_arr: jnp.ndarray,
+              layer: Optional[jnp.ndarray]) -> jnp.ndarray:
+    """The layer's (B,T,...) view of a stacked cache, or the cache itself."""
+    if layer is None:
+        return cache_arr
+    return jax.lax.dynamic_index_in_dim(cache_arr, layer, 0, keepdims=False)
 
 
 def attention_decode(p, cfg: ModelConfig, x: jnp.ndarray, cache: KVCache,
                      pos: jnp.ndarray, *,
                      num_kv: Optional[int] = None,
-                     window: int = 0) -> Tuple[jnp.ndarray, KVCache]:
-    """One-token decode. x: (B,1,d_model); pos: (B,) int32 tokens-so-far."""
+                     window: int = 0,
+                     layer: Optional[jnp.ndarray] = None
+                     ) -> Tuple[jnp.ndarray, KVCache]:
+    """One-token decode. x: (B,1,d_model); pos: (B,) int32 tokens-so-far.
+
+    ``cache`` holds one layer (B,T,Hkv,D), or with ``layer`` every layer
+    (L,B,T,Hkv,D); either way the returned cache differs from it only in the
+    one slot per row that this step writes."""
     num_kv = cfg.num_kv_heads if num_kv is None else num_kv
     B = x.shape[0]
     q, k, v = _project_qkv(p, cfg, x, num_kv)
@@ -293,13 +306,14 @@ def attention_decode(p, cfg: ModelConfig, x: jnp.ndarray, cache: KVCache,
         q = apply_rope(q, pos[:, None], cfg.rope_theta)
         k = apply_rope(k, pos[:, None], cfg.rope_theta)
     w = window or cfg.attention_window
-    cache_len = cache.k.shape[1]
+    cache_len = cache.k.shape[-3]
     slot = jnp.mod(pos, cache_len) if w else jnp.minimum(pos, cache_len - 1)
-    k_new = _write_cache(cfg, cache.k, k, slot)
-    v_new = _write_cache(cfg, cache.v, v, slot)
+    k_new = _write_cache(cache.k, k, slot, layer)
+    v_new = _write_cache(cache.v, v, slot, layer)
     valid = cache_positions(cfg.replace(attention_window=w), cache_len,
                             pos + 1)
-    out = decode_attention(q, k_new, v_new, valid,
+    out = decode_attention(q, _layer_of(k_new, layer),
+                           _layer_of(v_new, layer), valid,
                            use_pallas=cfg.use_pallas)
     out = out.reshape(B, 1, cfg.num_heads * cfg.head_dim)
     y = out @ p["wo"].astype(out.dtype)
@@ -443,13 +457,17 @@ def mla_forward(p, cfg: ModelConfig, x, positions,
 
 
 def mla_decode(p, cfg: ModelConfig, x, cache: MLACache,
-               pos: jnp.ndarray) -> Tuple[jnp.ndarray, MLACache]:
-    B = x.shape[0]
-    T = cache.c_kv.shape[1]
+               pos: jnp.ndarray, *,
+               layer: Optional[jnp.ndarray] = None
+               ) -> Tuple[jnp.ndarray, MLACache]:
+    """One-token MLA decode; ``cache`` and ``layer`` as in
+    ``attention_decode``."""
+    T = cache.c_kv.shape[-2]
     q_nope, q_rope, c_kv, k_rope = _mla_qkv(p, cfg, x, pos[:, None])
     slot = jnp.minimum(pos, T - 1)
-    c_new = _write_cache(cfg, cache.c_kv, c_kv, slot)
-    kr_new = _write_cache(cfg, cache.k_rope, k_rope, slot)
+    c_new = _write_cache(cache.c_kv, c_kv, slot, layer)
+    kr_new = _write_cache(cache.k_rope, k_rope, slot, layer)
     valid = (jnp.arange(T)[None] < (pos + 1)[:, None])[:, None, None]
-    y = _mla_attend(p, cfg, q_nope, q_rope, c_new, kr_new, valid)
+    y = _mla_attend(p, cfg, q_nope, q_rope, _layer_of(c_new, layer),
+                    _layer_of(kr_new, layer), valid)
     return y, MLACache(c_kv=c_new, k_rope=kr_new)

@@ -91,11 +91,6 @@ class ModelConfig:
     # execution
     use_pallas: bool = False         # True: Pallas kernels (TPU / interpret)
     remat: bool = True               # checkpoint layer bodies in training
-    # KV-cache write mechanism for decode: "onehot" (paper-era baseline,
-    # reads+writes the whole cache each step) or "scatter"
-    # (dynamic_update_slice, O(1) traffic — the optimized default; see
-    # EXPERIMENTS.md §Perf for the before/after).
-    kv_update: str = "onehot"
     # Full-sequence attention reference path: "naive" materializes the SxS
     # score matrix (baseline; what the Pallas kernel replaces on TPU);
     # "chunked" streams KV blocks with a running softmax (flash-style jnp) —

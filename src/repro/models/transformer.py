@@ -97,14 +97,19 @@ class DecoderOnlyLM:
             aux = jnp.zeros((), jnp.float32)
         return x + f, cache, aux
 
-    def _layer_decode(self, lp, x, cache, pos, *, moe: bool):
+    def _layer_decode(self, lp, x, cache, pos, *, moe: bool, layer=None):
+        """One layer's decode; with ``layer``, ``cache`` is the stacked
+        cache of every scanned layer and comes back with this layer's one
+        slot per row written."""
         cfg = self.cfg
         h = rms_norm(x, lp["attn_norm"], cfg.norm_eps, cfg.use_pallas)
         if cfg.use_mla:
-            a, new_cache = attn.mla_decode(lp["attn"], cfg, h, cache, pos)
+            a, new_cache = attn.mla_decode(lp["attn"], cfg, h, cache, pos,
+                                           layer=layer)
         else:
             a, new_cache = attn.attention_decode(
-                lp["attn"], cfg, h, cache, pos, window=cfg.attention_window)
+                lp["attn"], cfg, h, cache, pos, window=cfg.attention_window,
+                layer=layer)
         x = x + a
         h = rms_norm(x, lp["ffn_norm"], cfg.norm_eps, cfg.use_pallas)
         if moe:
@@ -196,13 +201,18 @@ class DecoderOnlyLM:
             x, nc = self._layer_decode(lp, x, c, pos, moe=False)
             new_prefix.append(nc)
 
-        def body(h, inp):
-            lp, c = inp
-            h, nc = self._layer_decode(lp, h, c, pos, moe=moe)
-            return h, nc
+        # the stacked cache rides in the carry, so each layer writes its
+        # slots in the donated buffer; as scanned xs/ys every layer's cache
+        # would be sliced out of the stack and written back whole
+        def body(carry, inp):
+            h, c = carry
+            lp, layer = inp
+            h, c = self._layer_decode(lp, h, c, pos, moe=moe, layer=layer)
+            return (h, c), None
 
-        x, new_caches = scan_layers(
-            body, x, (params["layers"], cache["scanned"]),
+        (x, new_caches), _ = scan_layers(
+            body, (x, cache["scanned"]),
+            (params["layers"], jnp.arange(self.n_scanned)),
             unroll=cfg.unroll_layers)
         logits = self._unembed(params, x)
         return logits, {"prefix": new_prefix, "scanned": new_caches}
