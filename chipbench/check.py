@@ -1,13 +1,16 @@
 """What decides ``correct``: the timed path's own outputs against the
-float32 reference.
+float32 reference of the configuration's block.
 
 Once the window has closed and its requests have finished, the client
 keeps serving the mix and ``Capture`` intercepts the compiled programs
 that ``JaxBackend.execute`` calls, without changing them: a few prefill
 calls, then ``K`` consecutive decode calls of the full device batch.
-Around each decode call it keeps the slot each row writes (before and
-after) and a fingerprint of every other slot. The reference then
-re-computes those calls from the same inputs (``chipbench.reference``).
+Around each decode call it keeps, for every array leaf of the cache, the
+slot each row writes (before and after) and a fingerprint of every other
+slot. A leaf of the stacked layers is (L, B, T, ...), and one of an
+unrolled prefix layer (B, T, ...) is taken as L = 1. The reference that
+the configuration names (``spec.Bench.reference``) then re-computes
+those calls from the same inputs.
 
 The numbers compared, each against its limit:
 
@@ -16,15 +19,15 @@ The numbers compared, each against its limit:
   program ranks first lies below the reference's best logit.
 - ``decode_logit_rel``, ``prefill_logit_rel``: the relative L2 gap of the
   served rows' logits, and of the captured prefill calls' logits.
-- ``cache_write_gap``: over the captured calls and the layers, the
-  largest relative L2 gap between the K (or V) the program wrote to the
-  rows' slots and the reference's.
+- ``cache_write_gap``: over the captured calls, the layers and the
+  cache's leaves, the largest relative L2 gap between what the program
+  wrote to the rows' slots and the reference's.
 
 A configuration compares the numbers it has limits for
 (``check_limits``), and always the exact ones:
 
-- ``cache_other_slots_changed``: (call, layer, row) triples whose cache
-  changed outside the written slot.
+- ``cache_other_slots_changed``: (call, leaf, layer, row) entries whose
+  cache changed outside the written slot.
 - ``requests_wrong_length``: requests due in the window that did not
   finish with exactly their output length.
 - ``window_compiles``: compilations from the ramp to the window's close.
@@ -37,8 +40,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import reference
-
 #: served tokens to compare at least, over the captured decode calls
 DECODE_TOKENS = 256
 #: prefill calls to capture (of two tokens or more)
@@ -48,33 +49,53 @@ EXACT = ("cache_other_slots_changed", "requests_wrong_length",
          "window_compiles")
 
 
-def _kv(cache):
-    """(k, v), each (L, B, T, Hkv, D): the decoder's stacked cache."""
-    if cache.get("prefix"):
-        raise ValueError("unrolled prefix layers are not covered")
-    return cache["scanned"].k, cache["scanned"].v
+def _stacked(tree) -> list:
+    """Every array leaf of a tree in the decoder cache's structure with
+    its layer axis first: the unrolled prefix layers' leaves as L = 1,
+    then the stacked layers'. Of the cache itself, (L, B, T, ...)."""
+    return ([a[None] for a in jax.tree.leaves(tree["prefix"])]
+            + jax.tree.leaves(tree["scanned"]))
+
+
+def _by_calls(slots: List[dict]):
+    """Each call's slots (the cache's structure, leaves (L, B, ...) or a
+    prefix layer's (B, ...)) as one tree with the calls after L."""
+    return {"prefix": jax.tree.map(lambda *a: jnp.stack(a),
+                                   *[c["prefix"] for c in slots]),
+            "scanned": jax.tree.map(lambda *a: jnp.stack(a, axis=1),
+                                    *[c["scanned"] for c in slots])}
 
 
 @jax.jit
-def _slots(k, v, slot):
-    rows = jnp.arange(k.shape[1])
-    return k[:, rows, slot], v[:, rows, slot]
+def _slots(cache, slot):
+    """The slot each row writes, in the cache's structure: (L, B, ...)
+    of a stacked leaf, (B, ...) of a prefix layer's."""
+    rows = jnp.arange(slot.shape[0])
+    return {"prefix": jax.tree.map(lambda a: a[rows, slot],
+                                   cache["prefix"]),
+            "scanned": jax.tree.map(lambda a: a[:, rows, slot],
+                                    cache["scanned"])}
+
+
+_UINT = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32}
 
 
 @jax.jit
-def _fingerprint(k, v, slot):
+def _fingerprint(cache, slot):
     """(L, B) position-weighted sums of the bits of every slot but the
-    written one: equal before and after exactly when nothing else
-    changed (up to a collision)."""
+    written one, for each leaf of ``_stacked``: equal before and after
+    exactly when nothing else changed (up to a collision)."""
     def fp(a):
-        bits = jax.lax.bitcast_convert_type(a, jnp.uint16).astype(jnp.uint32)
+        bits = jax.lax.bitcast_convert_type(
+            a, _UINT[a.dtype.itemsize]).astype(jnp.uint32)
         t = a.shape[2]
         w = (jnp.arange(bits[0, 0].size, dtype=jnp.uint32)
              * jnp.uint32(2654435761) + 1).reshape(bits.shape[2:])
         keep = jnp.arange(t)[None, :] != slot[:, None]          # (B, T)
-        return jnp.sum(bits * w * keep[None, :, :, None, None],
-                       axis=(2, 3, 4), dtype=jnp.uint32)
-    return fp(k), fp(v)
+        keep = keep.reshape(keep.shape + (1,) * (a.ndim - 3))
+        return jnp.sum(bits * w * keep[None], axis=tuple(range(2, a.ndim)),
+                       dtype=jnp.uint32)
+    return [fp(a) for a in _stacked(cache)]
 
 
 class Capture:
@@ -127,18 +148,16 @@ class Capture:
             if len(self.decode) >= self.k_calls:
                 return self._real_decode(params, token, cache, pos)
             slot = jnp.asarray(np.minimum(pos, t - 1))
-            k, v = _kv(cache)
-            fb = _fingerprint(k, v, slot)
-            old = _slots(k, v, slot)
+            fb = _fingerprint(cache, slot)
+            old = _slots(cache, slot)
             logits, new = self._real_decode(params, token, cache, pos)
-            k, v = _kv(new)
             self.decode.append({
                 "token": np.asarray(token)[:, 0], "pos": np.asarray(pos),
                 "served": len(self._plan.decode), "logits": logits[:, 0],
-                "old": old, "new": _slots(k, v, slot),
-                "fp_before": fb, "fp_after": _fingerprint(k, v, slot)})
+                "old": old, "new": _slots(new, slot),
+                "fp_before": fb, "fp_after": _fingerprint(new, slot)})
             if len(self.decode) == self.k_calls:
-                self.final = (k, v)
+                self.final = new
             return logits, new
 
         self.backend._decode = decode
@@ -167,32 +186,29 @@ def _rel(a, b, axes):
             / jnp.sqrt(jnp.sum(b * b, axes)))
 
 
-def readings(cap: Capture, weights, conf: dict,
+def readings(cap: Capture, weights, conf: dict, reference,
              control: bool = False) -> Dict[str, float]:
-    """The compared numbers of the captured calls. With ``control``, the
-    int8 reference takes the program's place and is read the same way."""
+    """The compared numbers of the captured calls, against ``reference``
+    (the configuration's module, ``spec.Bench.reference``). With
+    ``control``, the reference's control (the precision below the served
+    one) takes the program's place and is read the same way."""
     d = cap.decode
     tokens = np.stack([c["token"] for c in d])
     pos = np.stack([c["pos"] for c in d])
-    old_k = jnp.stack([c["old"][0] for c in d], axis=1)
-    old_v = jnp.stack([c["old"][1] for c in d], axis=1)
-    fk, fv = cap.final
-    ref, rk, rv = reference.decode(weights, conf, tokens, fk, fv, pos,
-                                   old_k, old_v)
+    old = _by_calls([c["old"] for c in d])
+    ref, rw = reference.decode(weights, conf, tokens, cap.final, pos, old)
     if control:
-        served, wk, wv = reference.decode(weights, conf, tokens, fk, fv,
-                                          pos, old_k, old_v, control=True)
-        wk, wv = jnp.stack(wk), jnp.stack(wv)
+        served, written = reference.decode(weights, conf, tokens, cap.final,
+                                           pos, old, control=True)
     else:
         served = jnp.stack([c["logits"] for c in d])
-        wk = jnp.stack([c["new"][0] for c in d], axis=1)
-        wv = jnp.stack([c["new"][1] for c in d], axis=1)
+        written = _by_calls([c["new"] for c in d])
     rows = np.arange(pos.shape[1])[None, :] < np.array(
         [c["served"] for c in d])[:, None]
     dgap = _gap(ref, served)
-    rk, rv = jnp.stack(rk), jnp.stack(rv)
-    # (L, K) relative gaps over each call's rows
-    wgap = jnp.maximum(_rel(wk, rk, (2, 3, 4)), _rel(wv, rv, (2, 3, 4)))
+    # each leaf's (L, K) relative gaps over each call's rows
+    wgap = max(float(jnp.max(_rel(w, r, tuple(range(2, w.ndim)))))
+               for w, r in zip(_stacked(written), _stacked(rw)))
     prels = []
     for c in cap.prefill:
         pref = reference.prefill(weights, conf, c["tokens"])
@@ -200,16 +216,15 @@ def readings(cap: Capture, weights, conf: dict,
                                      control=True)
                    if control else c["logits"])
         prels.append(float(_rel(pserved, pref, None)))
-    changed = sum(int(np.sum(np.asarray(c["fp_before"][i])
-                             != np.asarray(c["fp_after"][i])))
-                  for c in d for i in (0, 1))
+    changed = sum(int(np.sum(np.asarray(b) != np.asarray(a)))
+                  for c in d for b, a in zip(c["fp_before"], c["fp_after"]))
     sr = np.nonzero(rows)
     return {
         "decode_logit_gap": float(jnp.max(dgap[sr])),
         "decode_logit_rel": float(_rel(jnp.asarray(served)[sr], ref[sr],
                                        None)),
         "prefill_logit_rel": max(prels),
-        "cache_write_gap": float(jnp.max(wgap)),
+        "cache_write_gap": wgap,
         "cache_other_slots_changed": 0 if control else changed,
     }
 

@@ -16,7 +16,8 @@ A run:
 3. keeps serving, with the same load, until every request due in the
    window has finished (``DRAIN_CAP_S`` at most), reads the device's
    peak memory, then captures decode and prefill calls of the timed
-   path and compares them with the reference (``chipbench.check``);
+   path and compares them with the configuration's reference
+   (``chipbench.check``);
 4. with ``--trace 1``, profiles the last ``TRACE_S`` seconds of the
    window and reports the per-layer metrics instead of the end-to-end
    ones.
@@ -303,7 +304,8 @@ def run_cell(bench: spec.Bench, name: str, seed: int, seconds: float,
     backend.cache = None
     gc.collect()
     t = time.perf_counter()
-    numbers = check.readings(cap, weights, conf)
+    reference = bench.reference(conf)
+    numbers = check.readings(cap, weights, conf, reference)
     phases["reference"] = time.perf_counter() - t
     print("seconds " + json.dumps(phases), file=sys.stderr)
     print("readings " + json.dumps(numbers), file=sys.stderr)
@@ -311,7 +313,7 @@ def run_cell(bench: spec.Bench, name: str, seed: int, seconds: float,
     numbers["window_compiles"] = window_compiles
     checks = check.verdict(numbers, conf["check_limits"])
     if control:
-        ctl = check.readings(cap, weights, conf, control=True)
+        ctl = check.readings(cap, weights, conf, reference, control=True)
         print("control_readings " + json.dumps(ctl), file=sys.stderr)
         ctl_checks = check.verdict(ctl, conf["check_limits"])
 

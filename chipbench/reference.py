@@ -1,23 +1,42 @@
 """Plain float32 reference of the dense decoder that the configurations
 state, and its int8 control.
 
-It imports nothing of the program. It is given the benchmark's weights
-(``chipbench.weights``) and the inputs that the timed path was given,
-and computes layer by layer, so that it fits beside the weights:
+A configuration file names the module that recomputes its block
+(``"reference": "<module>"``, a file ``chipbench/<module>.py``; this one
+where it names none), and ``chipbench.check`` calls it with the
+benchmark's weights (``chipbench.weights``) and the inputs that the
+timed path was given. Every such module imports nothing of the program
+and gives:
 
-- ``decode``: consecutive decode calls of one device batch. Each call's
-  attention reads the program's own cache for the earlier positions and
-  the reference's own K/V for the position it writes, so the reference
-  follows the inputs the timed path was given, not its results.
-- ``prefill``: one causal forward over a token row.
+- ``decode(weights, conf, tokens, cache, pos, old, control=False) ->
+  (logits, written)``: K consecutive decode calls of one device batch.
+  ``tokens`` and ``pos`` (K, B): each call's input tokens and the
+  position each row writes. ``cache``: the program's cache after the
+  last call, in the program's structure (``{"prefix": [layer, ...],
+  "scanned": stacked}``, a stacked leaf (L, B, T, ...), a prefix
+  layer's (B, T, ...)). ``old``: the same structure, each leaf's slot
+  axis T replaced by what each call's slot held before it: (L, K, B,
+  ...), or (K, B, ...) for a prefix layer. Each call's attention reads
+  ``cache`` for the earlier positions and the reference's own entry for
+  the position it writes, so the reference follows the inputs the timed
+  path was given, not its results. Returns logits (K, B, V) and
+  ``written``, what the reference writes to each call's slot, in
+  ``old``'s structure.
+- ``prefill(weights, conf, tokens, control=False)``: logits (S, V) of
+  one causal forward over a token row ``tokens`` (S,).
 
-The model: token embedding; per layer RMSNorm -> Q/K/V projections ->
-rotary embedding (rotate-half) on Q and K -> grouped-query causal
-attention -> output projection -> residual; RMSNorm -> FFN (SwiGLU when
-gated, tanh-GELU otherwise) -> residual; final RMSNorm -> head (the
-embedding's transpose when tied).
+``control=True`` computes the same in the precision below the one the
+configuration serves in.
 
-``control=True`` runs every matmul of the layers and the head in int8
+This module computes layer by layer, so that it fits beside the
+weights. The model: token embedding; per layer RMSNorm -> Q/K/V
+projections -> rotary embedding (rotate-half) on Q and K ->
+grouped-query causal attention -> output projection -> residual; RMSNorm
+-> FFN (SwiGLU when gated, tanh-GELU otherwise) -> residual; final
+RMSNorm -> head (the embedding's transpose when tied). Its cache is one
+stack of K and V (``.k``, ``.v``), with no prefix layers.
+
+Its control runs every matmul of the layers and the head in int8
 (symmetric, per output channel for weights and per token for
 activations, W8A8), the precision below the bf16 that the
 configurations serve in.
@@ -174,27 +193,26 @@ def _logits(weights, conf, x, control):
                  tied=tied, control=control)
 
 
-def decode(weights, conf, tokens, cache_k, cache_v, pos, old_k, old_v,
-           control=False):
-    """Reference logits (K, B, V) and the K and V it writes at each layer
-    (two lists of (K, B, Hkv, D)) for K consecutive decode calls.
-
-    tokens (K, B): the calls' input tokens. cache_k, cache_v
-    (L, B, T, Hkv, D): the program's cache after the last call. pos
-    (K, B). old_k, old_v (L, K, B, Hkv, D): each call's slot contents
-    before it."""
+def decode(weights, conf, tokens, cache, pos, old, control=False):
+    """Reference logits (K, B, V) and what it writes to each call's slot
+    for K consecutive decode calls (the module's interface)."""
+    if cache["prefix"]:
+        raise ValueError("the dense decoder has no prefix layers")
+    kv, old_kv = cache["scanned"], old["scanned"]
     dims = _dims(conf)
     x = jnp.take(weights["embed"], jnp.asarray(tokens), axis=0).astype(F32)
     pos = jnp.asarray(pos)
     ks: List = []
     vs: List = []
     for i in range(conf["num_hidden_layers"]):
-        x, k, v = _decode_layer(_layer(weights, i), cache_k[i], cache_v[i],
-                                x, pos, old_k[i], old_v[i], dims=dims,
+        x, k, v = _decode_layer(_layer(weights, i), kv.k[i], kv.v[i], x,
+                                pos, old_kv.k[i], old_kv.v[i], dims=dims,
                                 control=control)
         ks.append(k)
         vs.append(v)
-    return _logits(weights, conf, x, control), ks, vs
+    written = {"prefix": [],
+               "scanned": old_kv._replace(k=jnp.stack(ks), v=jnp.stack(vs))}
+    return _logits(weights, conf, x, control), written
 
 
 def prefill(weights, conf, tokens, control=False):

@@ -1,7 +1,9 @@
 """``BENCHMARK.json`` against the benchmark's contract and its own files:
-every name it uses has its file, every per-layer metric moves one
-end-to-end metric that its cells report, and the entry refuses to run
-without a TPU."""
+every name it uses has its file (a configuration's reference module
+too, which takes nothing from the program), every per-layer metric moves
+one end-to-end metric that its cells report, and the entry refuses to
+run without a TPU."""
+import ast
 import json
 import os
 import re
@@ -23,8 +25,51 @@ def bench(root=ROOT):
     return spec.Bench(root)
 
 
-def check_layout(root: Path) -> None:
-    """Raise AssertionError where ``root``'s benchmark breaks a rule."""
+#: calls by which a module reads a file or imports a module by name
+FILE_READS = {"open", "load", "fromfile", "loadtxt", "read_bytes",
+              "read_text", "import_module", "__import__"}
+
+
+def program_uses(path: Path, bench_dir: Path, seen=None) -> list:
+    """What the module at ``path`` takes from outside its arguments: an
+    import of the program (``repro``), also through another module of the
+    benchmark's directory, and a call that reads a file or imports by
+    name. Empty for a module that computes from what it is given."""
+    seen = set() if seen is None else seen
+    if path in seen or not path.is_file():
+        return []
+    seen.add(path)
+    found = []
+    for node in ast.walk(ast.parse(path.read_text(), str(path))):
+        if isinstance(node, ast.Import):
+            names = [a.name for a in node.names]
+        elif isinstance(node, ast.ImportFrom):
+            base = "chipbench" if node.level else node.module or ""
+            names = [base] + [f"{base}.{a.name}" for a in node.names]
+        elif isinstance(node, ast.Call):
+            f = node.func
+            name = f.attr if isinstance(f, ast.Attribute) else \
+                getattr(f, "id", None)
+            if name in FILE_READS:
+                found.append(f"{path.name}:{node.lineno} calls {name}")
+            continue
+        else:
+            continue
+        for name in names:
+            top, _, rest = name.partition(".")
+            if top == "repro":
+                found.append(f"{path.name}:{node.lineno} imports {name}")
+            elif top == "chipbench" and rest:
+                found += program_uses(
+                    bench_dir / (rest.split(".")[0] + ".py"), bench_dir,
+                    seen)
+    return found
+
+
+def check_layout(root: Path, plumbing=()) -> None:
+    """Raise AssertionError where ``root``'s benchmark breaks a rule.
+    Reference modules named in ``plumbing`` may use the program: a
+    rehearsal's, which checks the harness and not a model."""
     b = bench(root)
     s = b.spec
     assert set(s) == {"command", "paths", "run_seconds", "configs",
@@ -67,6 +112,11 @@ def check_layout(root: Path) -> None:
     for c in confs.values():
         assert c["file"].startswith(s["paths"][0] + "/")
         assert b.config(c["name"])["reduced"] == c["reduced"]
+        ref = b.config(c["name"]).get("reference", "reference")
+        assert (b.dir / f"{ref}.py").is_file(), ref
+        if ref not in plumbing:
+            uses = program_uses(b.dir / f"{ref}.py", b.dir)
+            assert not uses, (ref, uses)
         assert any(w["config"] == c["name"] for w in cells.values())
 
 
@@ -92,6 +142,43 @@ def test_a_missing_file_is_caught(tmp_path):
     (tmp_path / "chipbench/traffic/longctx.json").unlink()
     with pytest.raises(FileNotFoundError):
         check_layout(tmp_path)
+
+
+def test_a_missing_reference_is_caught(tmp_path):
+    shutil.copytree(ROOT / "chipbench", tmp_path / "chipbench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
+    path = tmp_path / "chipbench/configs/phi3-medium-14b.json"
+    conf = json.loads(path.read_text())
+    conf["reference"] = "reference_of_another_block"
+    path.write_text(json.dumps(conf))
+    with pytest.raises(AssertionError, match="reference_of_another_block"):
+        check_layout(tmp_path)
+
+
+@pytest.mark.parametrize("source, use", [
+    ("from repro.models import build_model\n", "imports repro.models"),
+    ("import repro.models.blocks as blocks\n", "imports repro.models"),
+    ("from chipbench import spec\n", "imports repro.configs"),
+    ("import numpy as np\nW = np.load('w.npy')\n", "calls load"),
+    ("import importlib\nm = importlib.import_module('re' + 'pro')\n",
+     "calls import_module"),
+])
+def test_a_reference_that_uses_the_program_is_caught(tmp_path, source, use):
+    """A reference that imports the program, directly or through a module
+    of the benchmark, or reads weights or tables from a file."""
+    shutil.copytree(ROOT / "chipbench", tmp_path / "chipbench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
+    path = tmp_path / "chipbench/configs/phi3-medium-14b.json"
+    conf = json.loads(path.read_text())
+    conf["reference"] = "reference_of_the_program"
+    path.write_text(json.dumps(conf))
+    (tmp_path / "chipbench/reference_of_the_program.py").write_text(
+        (ROOT / "chipbench/reference.py").read_text() + source)
+    with pytest.raises(AssertionError, match=use):
+        check_layout(tmp_path)
+    check_layout(tmp_path, plumbing={"reference_of_the_program"})
 
 
 def test_a_metric_moving_an_unreported_metric_is_caught(tmp_path):
