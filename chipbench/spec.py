@@ -105,11 +105,11 @@ FIELDS = {
 }
 
 #: published keys whose value the program implies while it has no field
-#: for them: a file stating another value raises, unless ``ModelConfig``
-#: has a field of the key's name and the value is a plain scalar (``None``,
-#: a bool, a number or a string), which the field then takes as it is. A
-#: list or an object, such as a YaRN ``rope_scaling``, is refused even
-#: then: its translation into the field's type is defined with the field.
+#: for them: a file stating another value raises. Where ``ModelConfig`` has
+#: a field of the key's name, the field takes the file's value as stated,
+#: whatever its type (an object such as YaRN's ``rope_scaling`` too: how the
+#: program holds it is decided with the field), and the value here where
+#: the file leaves the key out, never the program registry's.
 IMPLIED = {
     "rope_scaling": None, "norm_topk_prob": True,
     "scoring_func": "softmax", "topk_method": "greedy",
@@ -117,7 +117,6 @@ IMPLIED = {
     "n_group": 1, "topk_group": 1, "norm_type": "rms_norm",
     "use_bias": False, "attention_bias": False,
 }
-SCALARS = (type(None), bool, int, float, str)
 
 
 def fields(conf: dict) -> dict:
@@ -170,19 +169,17 @@ def attention_window(conf: dict) -> int:
 
 def model_config(conf: dict):
     """The program's ``ModelConfig`` for a configuration file: the
-    registry's entry for ``arch`` with every field of ``FIELDS`` taken
-    from the file. Raises where the file states what the program cannot
-    run."""
+    registry's entry for ``arch`` with every field of ``FIELDS`` and every
+    field named by an ``IMPLIED`` key taken from the file. Raises where
+    the file states what the program cannot run."""
     from repro.configs import get_config
     base = get_config(conf["arch"])
     default = {f.name: f.default for f in dataclasses.fields(base)}
     kw = {field: default[field] for field in FIELDS.values()}
     kw.update(fields(conf))
     for key, implied in IMPLIED.items():
-        if key not in conf:
-            continue
-        value = conf[key]
-        if key in default and isinstance(value, SCALARS):
+        value = conf.get(key, implied)
+        if key in default:
             kw[key] = value
         elif value != implied:
             raise ValueError(f"the program cannot run {key!r} as the file "

@@ -4,7 +4,6 @@ only implies either passed to a field of its name or refused."""
 import dataclasses
 import json
 from pathlib import Path
-from typing import Optional
 
 import pytest
 
@@ -73,38 +72,97 @@ def test_the_dense_files_build_what_they_built_before(name):
     assert spec.model_config(conf) == before
 
 
+def program(monkeypatch, *names, **registry):
+    """Install a stand-in for the program: a frozen dataclass with
+    ``ModelConfig``'s fields less every key of ``spec.IMPLIED``, plus a
+    field for each of ``names`` whose default is ``IMPLIED``'s value. Its
+    registry entry for an arch is the real one's, with ``registry``'s
+    values in those fields."""
+    import repro.configs
+    real = repro.configs.get_config
+    kept = [f for f in dataclasses.fields(ModelConfig)
+            if f.name not in spec.IMPLIED]
+    stand_in = dataclasses.make_dataclass(
+        "Program",
+        [(f.name, f.type, dataclasses.field(
+            default=f.default, default_factory=f.default_factory))
+         for f in kept]
+        + [(name, object, dataclasses.field(default=spec.IMPLIED[name]))
+           for name in names],
+        frozen=True,
+        namespace={"replace": lambda self, **kw: dataclasses.replace(
+            self, **kw)})
+    monkeypatch.setattr(repro.configs, "get_config", lambda arch: stand_in(
+        **{f.name: getattr(real(arch), f.name) for f in kept}, **registry))
+
+
 @pytest.mark.parametrize("key, value", [
     ("rope_scaling", None), ("norm_topk_prob", None),
     ("scoring_func", "sigmoid"), ("q_lora_rank", 1536),
     ("routed_scaling_factor", 2.5), ("use_bias", True),
     ("norm_type", "layer_norm")])
-def test_a_value_the_program_cannot_run_is_refused(key, value):
+def test_a_value_the_program_cannot_run_is_refused(monkeypatch, key, value):
     """The published YaRN scaling and ``norm_topk_prob: false`` as the
-    file states them (``None``), or another value the program has no
-    field for."""
+    file states them (``None``), or another value, on a program that has
+    no field for the key."""
+    program(monkeypatch)
     conf = deepseek(**RUNNABLE)
     conf[key] = deepseek()[key] if value is None else value
     with pytest.raises(ValueError, match=key):
         spec.model_config(conf)
 
 
-@dataclasses.dataclass(frozen=True)
-class _WithFields(ModelConfig):
-    """A program that has the two fields."""
-    rope_scaling: Optional[object] = None
-    norm_topk_prob: bool = True
-
-
 def test_a_field_of_the_keys_name_takes_the_files_value(monkeypatch):
-    """A scalar goes to the field as the file states it; an object, such
-    as YaRN's ``rope_scaling``, is still refused: its translation into
-    the field's type comes with the field."""
-    import repro.configs
-    base = repro.configs.get_config("deepseek-v2-lite-16b")
-    monkeypatch.setattr(repro.configs, "get_config", lambda arch: _WithFields(
-        **{f.name: getattr(base, f.name) for f in dataclasses.fields(base)}))
+    """A scalar and an object, such as YaRN's ``rope_scaling``, go to the
+    field as the file states them."""
+    program(monkeypatch, "rope_scaling", "norm_topk_prob")
     cfg = spec.model_config(deepseek(rope_scaling=None))
     assert cfg.norm_topk_prob is False
     assert cfg.rope_scaling is None
-    with pytest.raises(ValueError, match="rope_scaling"):
-        spec.model_config(deepseek())
+    cfg = spec.model_config(deepseek())
+    assert cfg.rope_scaling == deepseek()["rope_scaling"]
+    assert cfg.rope_scaling["type"] == "yarn"
+
+
+def test_the_published_deepseek_file_builds_on_a_program_with_the_fields(
+        monkeypatch):
+    """The unchanged file, YaRN and ``norm_topk_prob: false`` included,
+    builds once the program has a field for every implied key, and each
+    field holds the file's value (the implied one for ``norm_type`` and
+    ``use_bias``, which the file leaves out)."""
+    program(monkeypatch, *spec.IMPLIED)
+    published = deepseek()
+    cfg = spec.model_config(published)
+    assert cfg.rope_scaling == published["rope_scaling"]
+    assert cfg.norm_topk_prob is False
+    for key, implied in spec.IMPLIED.items():
+        assert getattr(cfg, key) == published.get(key, implied), key
+    assert (cfg.num_experts, cfg.kv_lora_rank) == (64, 512)
+
+
+def test_moonlight_router_values_reach_fields_of_their_names(monkeypatch):
+    """Moonlight-16B-A3B's router (catalog ``Moonlight-16B-A3B``): sigmoid
+    scores, ``noaux_tc`` top-k and a scaling of 2.446."""
+    router = {"scoring_func": "sigmoid", "topk_method": "noaux_tc",
+              "routed_scaling_factor": 2.446}
+    program(monkeypatch, *router)
+    cfg = spec.model_config(deepseek(**RUNNABLE, **router))
+    assert {k: getattr(cfg, k) for k in router} == router
+
+
+@pytest.mark.parametrize("key, registry", [
+    ("norm_topk_prob", False),
+    ("rope_scaling", deepseek()["rope_scaling"]),
+    ("scoring_func", "sigmoid"), ("routed_scaling_factor", 2.446),
+    ("q_lora_rank", 1536), ("use_bias", True)])
+def test_a_key_the_file_leaves_out_takes_the_implied_value(
+        monkeypatch, key, registry):
+    """Not the registry entry's value: a file on an arch whose entry runs
+    another value runs the implied one, as it says by leaving the key
+    out."""
+    import repro.configs
+    program(monkeypatch, key, **{key: registry})
+    conf = deepseek(**RUNNABLE)
+    conf.pop(key, None)
+    assert getattr(repro.configs.get_config(conf["arch"]), key) == registry
+    assert getattr(spec.model_config(conf), key) == spec.IMPLIED[key]
